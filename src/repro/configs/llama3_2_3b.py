@@ -1,6 +1,6 @@
 """llama3.2-3b: small llama3, tied embeddings.
 
-[hf:meta-llama/Llama-3.2-1B; unverified] 28L d_model=3072 24H (kv=8)
+[hf:meta-llama/Llama-3.2-3B config.json] 28L d_model=3072 24H (kv=8)
 d_ff=8192 vocab=128256.
 """
 from repro.models.config import ModelConfig
@@ -19,5 +19,5 @@ CONFIG = ModelConfig(
     norm="rmsnorm",
     rope_theta=500_000.0,
     tie_embeddings=True,
-    source="hf:meta-llama/Llama-3.2-1B; unverified",
+    source="hf:meta-llama/Llama-3.2-3B config.json",
 )

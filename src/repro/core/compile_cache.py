@@ -18,30 +18,62 @@ Cache levels (best effort, graceful degradation):
   L0  miss -> full trace+lower+compile, then populate L1+L2.
 
 Keys: sha256 over (image digest, step kind, mesh fingerprint, abstract input
-signature, jax/jaxlib versions, backend) -- the exact analog of an image
-digest pinning a bit-exact environment. A key never collides across meshes or
-framework versions, so a cache is safely shareable cluster-wide (the paper's
-registry role).
+signature, jax version, backend, device kind, digest of this package's
+sources) -- the exact analog of an image digest pinning a bit-exact
+environment. A key never collides across meshes, chips, framework versions or
+code revisions, so a cache is safely shareable cluster-wide (the paper's
+registry role) and survives from one run to the next at a fixed path
+(``resolve_cache_dir``).
 """
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
 import pickle
+import tempfile
 import time
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Callable
 
 import jax
+from jax.experimental import serialize_executable as _se
 
-try:  # L1 support
-    from jax.experimental import serialize_executable as _se
-    _HAVE_SERIALIZE = True
-except Exception:  # pragma: no cover
-    _HAVE_SERIALIZE = False
+_PACKAGE = Path(__file__).resolve().parents[1]     # src/repro
+CHECKOUT = _PACKAGE.parents[1]
+
+
+def resolve_cache_dir() -> Path:
+    """The one place a Runtime's CompileCache lives.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, names the directory; JAX reads
+    the same variable for its own persistent cache, so nothing is set here.
+    Otherwise the cache lives at ``<checkout>/.stevedore/compile-cache`` and
+    JAX's persistent cache is pointed there too. The path never depends on
+    the runtime root, a temporary name, the pid or the time: a cache whose
+    directory moves never hits."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return Path(env)
+    path = CHECKOUT / ".stevedore" / "compile-cache"
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", str(path))
+    return path
+
+
+@functools.cache
+def _code_digest() -> str:
+    """sha256 over the package's sources. A persistent cache outlives the
+    code that filled it; keying on the sources keeps an executable traced
+    from older code from answering for newer code."""
+    h = hashlib.sha256()
+    for f in sorted(_PACKAGE.rglob("*.py")):
+        h.update(str(f.relative_to(_PACKAGE)).encode())
+        h.update(f.read_bytes())
+    return h.hexdigest()
 
 
 def mesh_fingerprint(mesh: jax.sharding.Mesh) -> str:
@@ -87,6 +119,8 @@ class CompileCache:
                 "sig": abstract_signature(args_tree),
                 "jax": jax.__version__,
                 "backend": jax.default_backend(),
+                "device_kind": jax.devices()[0].device_kind,
+                "code": _code_digest(),
             },
             sort_keys=True,
         )
@@ -115,13 +149,18 @@ class CompileCache:
         p_exec, p_hlo, p_meta = self._paths(key)
 
         # L1: full executable
-        if want_executable and _HAVE_SERIALIZE and p_exec.exists():
+        if want_executable and p_exec.exists():
             t0 = time.perf_counter()
             try:
                 payload = pickle.loads(p_exec.read_bytes())
+                # load onto the devices it was compiled for: by default JAX
+                # loads onto every device of the host, which a step built on
+                # a sub-mesh (one chip of four) cannot run on
+                by_id = {d.id: d for d in jax.devices()}
                 compiled = _se.deserialize_and_load(
-                    payload["serialized"], payload["in_tree"], payload["out_tree"]
-                )
+                    payload["serialized"], payload["in_tree"],
+                    payload["out_tree"],
+                    execution_devices=[by_id[i] for i in payload["devices"]])
                 self.stats.hits_l1 += 1
                 self.stats.last_level = "L1"
                 self.stats.last_seconds = time.perf_counter() - t0
@@ -142,17 +181,19 @@ class CompileCache:
             _atomic_bytes(p_hlo, lowered.as_text().encode())
         except Exception:
             pass
-        if _HAVE_SERIALIZE:
-            try:
-                serialized, in_tree, out_tree = _se.serialize(compiled)
-                _atomic_bytes(
-                    p_exec,
-                    pickle.dumps(
-                        {"serialized": serialized, "in_tree": in_tree, "out_tree": out_tree}
-                    ),
-                )
-            except Exception:
-                pass
+        try:
+            serialized, in_tree, out_tree = _se.serialize(compiled)
+            devices = [d.id for d in
+                       compiled.runtime_executable().local_devices()]
+            _atomic_bytes(
+                p_exec,
+                pickle.dumps(
+                    {"serialized": serialized, "in_tree": in_tree,
+                     "out_tree": out_tree, "devices": devices}
+                ),
+            )
+        except Exception:
+            pass
         _atomic_bytes(
             p_meta,
             json.dumps(
@@ -177,6 +218,15 @@ class CompileCache:
 
 
 def _atomic_bytes(path: Path, data: bytes) -> None:
-    tmp = path.with_suffix(path.suffix + ".tmp")
-    tmp.write_bytes(data)
-    os.replace(tmp, path)
+    """Write through a temporary file of its own in the same directory, then
+    rename: processes sharing a cache (fabric workers, test workers) never
+    interleave their bytes, and a reader sees a whole file or none."""
+    fd, tmp = tempfile.mkstemp(dir=path.parent, prefix=f".{path.name}.",
+                               suffix=".tmp")
+    try:
+        with os.fdopen(fd, "wb") as f:
+            f.write(data)
+        os.replace(tmp, path)
+    except BaseException:
+        Path(tmp).unlink(missing_ok=True)
+        raise

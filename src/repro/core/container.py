@@ -95,6 +95,7 @@ class Container:
             act_dtype=jnp.dtype(cfg["precision"].get("compute", "bfloat16")),
             moe_mesh=self.mesh if (moe_impl == "spmd" and tp > 1
                                    and self.arch.n_experts) else None,
+            mesh=self.mesh,
         )
         self.param_dtype = jnp.dtype(cfg["precision"].get("params", "float32"))
         self.cache_dtype = jnp.dtype(cfg["precision"].get("compute", "bfloat16"))

@@ -5,8 +5,10 @@
     c = rt.run("stable", platform="local")     # -> Container
     rt.images(); rt.ps()
 
-The Runtime owns the registry, the compile cache (shared across containers,
-like the paper's per-node image mount), and the overlay root.
+The Runtime owns the registry, the compile cache (shared across containers
+and runtimes, like the paper's per-node image mount; where it lives is decided
+by ``compile_cache.resolve_cache_dir``, never by the root), and the overlay
+root.
 """
 
 from __future__ import annotations
@@ -15,7 +17,7 @@ import json
 import os
 from pathlib import Path
 
-from repro.core.compile_cache import CompileCache
+from repro.core.compile_cache import CompileCache, resolve_cache_dir
 from repro.core.container import Container
 from repro.core.image import EnvImage
 from repro.core.imagefile import parse_imagefile
@@ -26,7 +28,7 @@ class Runtime:
     def __init__(self, root: str | os.PathLike = ".stevedore"):
         self.root = Path(root)
         self.registry = Registry(self.root / "registry")
-        self.compile_cache = CompileCache(self.root / "compile-cache")
+        self.compile_cache = CompileCache(resolve_cache_dir())
         self.overlay_root = self.root / "overlays"
 
     # -- images ------------------------------------------------------------

@@ -36,10 +36,8 @@ def make_platform_mesh(platform: str = "local") -> Mesh:
         ) from None
     axes = spec["axes"]
     shape = spec["shape"] or (jax.device_count(), 1)
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.5
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh: Mesh) -> tuple[str, ...]:

@@ -8,20 +8,28 @@ import jax
 
 from repro.kernels.matmul.kernel import matmul_pallas
 
-# platform -> (block_m, block_n, block_k); chosen for VMEM size & MXU shape
+# TPU device_kind (as JAX reports it) -> (block_m, block_n, block_k); chosen
+# for VMEM size & MXU shape. Off the TPU the kernel runs in interpret mode.
 BLOCK_TABLE = {
-    "tpu-v5e": (512, 512, 512),
-    "tpu-v4": (512, 1024, 512),
+    "TPU v5 lite": (512, 512, 512),     # v5e
+    "TPU v4": (512, 1024, 512),
     "cpu-interpret": (128, 128, 128),   # keep interpret-mode tests fast
 }
 
 
 def _platform() -> str:
-    return "tpu-v5e" if jax.default_backend() == "tpu" else "cpu-interpret"
+    if jax.default_backend() != "tpu":
+        return "cpu-interpret"
+    return jax.devices()[0].device_kind
 
 
 def matmul(a: jax.Array, b: jax.Array, platform: str | None = None) -> jax.Array:
-    bm, bn, bk = BLOCK_TABLE[platform or _platform()]
+    platform = platform or _platform()
+    if platform not in BLOCK_TABLE:
+        # a chip without a tuned entry is an error, not another chip's blocks
+        raise ValueError(f"no matmul block table for {platform!r}; "
+                         f"known: {sorted(BLOCK_TABLE)}")
+    bm, bn, bk = BLOCK_TABLE[platform]
     while a.shape[0] % bm:
         bm //= 2
     while b.shape[1] % bn:

@@ -26,8 +26,9 @@ Schedule (vs flash_attention/kernel.py):
   are fetched once per kv head, never replicated per q head.
 
 Tiling note: the q tile's sublane dim is the GQA group size (often < 8) --
-legal but sub-tile on real TPU; the CI oracle runs interpret=True where
-tiling does not apply.
+sub-tile, but the v5e compiler accepts it (tests/test_chip_compile.py) and
+chip_smoke.py runs it against the reference on the chip; the CPU tests run
+interpret=True, where tiling does not apply.
 """
 
 from __future__ import annotations

@@ -34,7 +34,9 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro.dist.mesh import batch_axes
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_rope
 from repro.models.params import ParamDef
@@ -295,7 +297,7 @@ def kv_cache_specs(window: int = 0):
 def paged_decode_attn(p: dict, x: jax.Array, layer_cache: dict,
                       idx: jax.Array, page_table: jax.Array,
                       cfg: ModelConfig, geom: AttnGeometry,
-                      window: int = 0):
+                      window: int = 0, mesh=None):
     """One-token decode over a PAGED KV pool (slot-granular only).
 
     x: (B,1,D); layer_cache k/v: (n_kv, n_pages, page_size, hd) -- the
@@ -307,7 +309,11 @@ def paged_decode_attn(p: dict, x: jax.Array, layer_cache: dict,
     dispatch -- alloc-on-write happens host-side in the PagePool), then
     attends through repro.kernels.paged_attention (Pallas on TPU, XLA
     oracle elsewhere). Free slots write through table rows reset to the
-    garbage page; their output is discarded by the host."""
+    garbage page; their output is discarded by the host.
+
+    On a ``mesh`` of several devices the attention call runs under
+    ``shard_map``: XLA cannot partition a Pallas call, so each device runs
+    it on its own batch rows against the whole pool."""
     if window:
         raise NotImplementedError(
             "paged decode supports full attention only (ring-buffer windows "
@@ -327,8 +333,16 @@ def paged_decode_attn(p: dict, x: jax.Array, layer_cache: dict,
         knew.astype(layer_cache["k"].dtype))
     cv = layer_cache["v"].at[:, page, off].set(
         vnew.astype(layer_cache["v"].dtype))
-    ctx = paged_attention(q[:, 0], ck, cv, page_table,
-                          idx.astype(jnp.int32) + 1, window=window)
+    lengths = idx.astype(jnp.int32) + 1
+    if mesh is None or mesh.size == 1:
+        ctx = paged_attention(q[:, 0], ck, cv, page_table, lengths)
+    else:
+        shards = math.prod(mesh.shape[a] for a in batch_axes(mesh))
+        rows = P(batch_axes(mesh)) if B % shards == 0 else P()
+        ctx = jax.shard_map(
+            paged_attention, mesh=mesh,
+            in_specs=(rows, P(), P(), rows, rows), out_specs=rows,
+            check_vma=False)(q[:, 0], ck, cv, page_table, lengths)
     return attn_out(p, ctx[:, None]), {"k": ck, "v": cv}
 
 

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist.compat import shard_map as _shard_map
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_mlp, mlp_defs
 from repro.models.params import ParamDef
@@ -229,7 +228,7 @@ def moe_forward_spmd(p: dict, x: jax.Array, cfg: ModelConfig, mesh: Mesh,
     manual = set(baxes) | {model_axis}
     pspecs = moe_param_specs(cfg, model_axis)
     xspec = (P(baxes if len(baxes) > 1 else baxes[0]) if baxes else P())
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda pl, xl: _moe_local(pl, xl, cfg, model_axis, baxes, tp),
         mesh=mesh,
         in_specs=(pspecs, xspec),

@@ -116,13 +116,14 @@ class Model:
     """A config bound to a TP width (for head padding / kv replication).
 
     ``constrain`` is an optional ``fn(x, logical_axes) -> x`` injected by the
-    distribution layer; the model never sees the mesh directly.
+    distribution layer. The model sees the mesh only where a kernel must run
+    per shard (``moe_mesh``, ``mesh``).
     """
 
     def __init__(self, cfg: ModelConfig, tp: int = 1,
                  constrain: Callable | None = None,
                  remat: str = "none", act_dtype=jnp.bfloat16,
-                 moe_mesh=None):
+                 moe_mesh=None, mesh=None):
         self.cfg = cfg
         self.geom = resolve_geometry(cfg, tp) if cfg.n_heads else None
         self.stages = build_stages(cfg)
@@ -134,6 +135,9 @@ class Model:
         # manual over the batch axes (explicit-ABI path)
         self.moe_mesh = moe_mesh
         self.moe_batch_axes = None
+        # the device mesh, for kernels XLA cannot partition (the paged
+        # attention Pallas call runs per shard); None -> one device
+        self.mesh = mesh
 
     # -- params ---------------------------------------------------------
     def param_defs(self) -> dict:
@@ -492,7 +496,7 @@ class Model:
                     if page_table is not None:
                         out, nc = attn_mod.paged_decode_attn(
                             p["attn"], h, c, idx, page_table, cfg, geom,
-                            window)
+                            window, mesh=self.mesh)
                     else:
                         out, nc = attn_mod.decode_attn(p["attn"], h, c, idx,
                                                        cfg, geom, window)

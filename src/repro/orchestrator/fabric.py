@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import base64
 import bisect
+import glob
 import json
 import os
 import signal
@@ -64,7 +65,9 @@ from pathlib import Path
 from queue import Empty, Queue
 from typing import Callable, Iterable
 
+import jax
 import numpy as np
+from jax._src import xla_bridge
 
 from repro.orchestrator.obs.metrics import MetricsRegistry, merge_snapshots
 from repro.orchestrator.obs.tracing import TraceBuffer, dump_span_log
@@ -445,19 +448,61 @@ def loopback_spawner(runtime, image, *, pod_kwargs: dict | None = None,
     return spawn
 
 
+def _host_chips() -> int:
+    """TPU chips attached to this host, counted from their device files
+    (what libtpu opens) so that this process never claims one to count."""
+    return len(glob.glob("/dev/accel[0-9]*") + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _check_worker_gets_chip(live_workers: int) -> None:
+    """Refuse to start a worker process that cannot get a chip of its own.
+
+    A chip belongs to one process at a time, and a process that initialises
+    JAX on a TPU host claims every chip of the host. A worker started while
+    this process holds the chips, or beside a live worker on a host with
+    chips, would fail or hang waiting for them, so both raise here instead.
+    CPU workers share the host freely: a parent already on the CPU backend
+    passes its environment on, ``JAX_PLATFORMS=cpu`` says so up front, and a
+    host with no chip has nothing to claim."""
+    if xla_bridge.backends_are_initialized():
+        platform = jax.default_backend()
+        if platform != "cpu":
+            raise RuntimeError(
+                f"--fabric proc: this process already holds the {platform} "
+                "backend, so a worker process cannot get a chip; serve "
+                "in-process (--fabric loopback) or start the workers before "
+                "this process touches JAX")
+        return
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        return
+    chips = _host_chips()
+    if chips and live_workers >= 1:
+        raise RuntimeError(
+            f"--fabric proc: {live_workers + 1} workers on a host of {chips} "
+            "chips, but each worker claims every chip, so only one can run; "
+            "set JAX_PLATFORMS=cpu for CPU workers or serve with --fabric "
+            "loopback")
+
+
 def proc_spawner(root, *, imagefile: str | None = None,
                  ref: str | None = None,
                  pod_kwargs: dict | None = None, fairness_cap: int = 4,
                  python: str | None = None) -> Callable:
     """Spawn callable launching one worker PROCESS per pod. The worker
-    re-opens the same runtime root (registry, compile cache, state dir)
+    re-opens the same runtime root (registry, state dir) and compile cache
     and resolves the image itself: an ``imagefile`` text is rebuilt
     (content-addressed -- every worker lands on the identical digest the
-    parent built), a registry ``ref`` is pulled."""
+    parent built), a registry ``ref`` is pulled. A worker that could not
+    get a chip of its own is refused before it starts
+    (``_check_worker_gets_chip``)."""
     if (imagefile is None) == (ref is None):
         raise ValueError("proc_spawner needs exactly one of imagefile=/"
                          "ref=")
+    started: list[ProcTransport] = []
+
     def spawn(pod_id: str, start_tick: int) -> ProcTransport:
+        _check_worker_gets_chip(
+            sum(t.proc.poll() is None for t in started))
         cfg = {"root": str(root), "imagefile": imagefile, "ref": ref,
                "pod_id": pod_id, "start_tick": int(start_tick),
                "fairness_cap": int(fairness_cap),
@@ -470,7 +515,8 @@ def proc_spawner(root, *, imagefile: str | None = None,
         pp = env.get("PYTHONPATH", "")
         if src not in pp.split(os.pathsep):
             env["PYTHONPATH"] = src + (os.pathsep + pp if pp else "")
-        return ProcTransport(argv, env=env)
+        started.append(ProcTransport(argv, env=env))
+        return started[-1]
     return spawn
 
 

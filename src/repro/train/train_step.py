@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.dist.compat import shard_map as _shard_map
 from repro.core.abi import CollectiveABI
 from repro.dist.mesh import batch_axes
 from repro.dist.sharding import ShardingRules, constrain
@@ -211,7 +210,7 @@ class TrainStepBuilder:
             ospec = ospec_for(opt_state)
             bspec_tree = jax.tree.map(lambda _: bspec, batch)
             mspec = {"loss": rep, "aux_loss": rep, "grad_norm": rep, "lr": rep}
-            return _shard_map(
+            return jax.shard_map(
                 local_step, mesh=self.mesh,
                 in_specs=(pspec, ospec, bspec_tree),
                 out_specs=(pspec, ospec, mspec),

@@ -1,47 +1,6 @@
 # NOTE: no XLA_FLAGS here on purpose -- smoke tests and benches must see the
 # real 1-CPU device. Only launch/dryrun.py forces 512 host devices, and only
 # in its own process. Multi-device tests spawn subprocesses with the flag.
-import sys
-import types
-
 import jax
-import pytest
 
 jax.config.update("jax_enable_x64", False)
-
-# Property-based tests use hypothesis when available; in hermetic images
-# without it we install a shim so the rest of the suite still collects and
-# runs (the @given tests skip instead of killing collection).
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    class _AnyStrategy:
-        def __call__(self, *a, **k):
-            return self
-
-        def __getattr__(self, name):
-            return self
-
-        def __or__(self, other):
-            return self
-
-    def _given(*a, **k):
-        def deco(fn):
-            def skipped(*args, **kwargs):
-                pytest.skip("hypothesis not installed")
-            skipped.__name__ = fn.__name__
-            skipped.__doc__ = fn.__doc__
-            return skipped
-        return deco
-
-    def _settings(*a, **k):
-        return lambda fn: fn
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = types.ModuleType("hypothesis.strategies")
-    _st = _AnyStrategy()
-    _hyp.strategies.__getattr__ = lambda name: _st
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _hyp.strategies

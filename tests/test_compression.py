@@ -99,12 +99,8 @@ import jax, jax.numpy as jnp
 from repro.train.compression import powersgd_init, powersgd_sync
 from repro.launch.analysis import parse_collectives
 
-from repro.dist.compat import shard_map
-if hasattr(jax.sharding, "AxisType"):
-    mesh = jax.make_mesh((4,), ("data",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-else:
-    mesh = jax.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 g = {"w": jax.random.normal(jax.random.key(0), (512, 512))}
 st = powersgd_init(g, 4)
 
@@ -115,12 +111,12 @@ def psgd(gl, stl):
     return powersgd_sync(gl, stl, ("data",), 4)
 
 from jax.sharding import PartitionSpec as Psp
-sm_plain = shard_map(plain, mesh=mesh, in_specs=(Psp(),),
-                     out_specs=Psp(), axis_names={"data"},
-                     check_vma=False)
-sm_psgd = shard_map(psgd, mesh=mesh, in_specs=(Psp(), Psp()),
-                    out_specs=(Psp(), Psp()), axis_names={"data"},
-                    check_vma=False)
+sm_plain = jax.shard_map(plain, mesh=mesh, in_specs=(Psp(),),
+                         out_specs=Psp(), axis_names={"data"},
+                         check_vma=False)
+sm_psgd = jax.shard_map(psgd, mesh=mesh, in_specs=(Psp(), Psp()),
+                        out_specs=(Psp(), Psp()), axis_names={"data"},
+                        check_vma=False)
 co_plain = jax.jit(sm_plain).lower(g).compile()
 co_psgd = jax.jit(sm_psgd).lower(g, st).compile()
 w_plain = parse_collectives(co_plain.as_text()).wire_bytes

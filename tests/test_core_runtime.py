@@ -2,13 +2,18 @@
 + data pipeline determinism."""
 
 import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.compilation_cache import compilation_cache
 
-from repro.core.compile_cache import CompileCache
+from repro.core.compile_cache import (CHECKOUT, CompileCache, _atomic_bytes,
+                                      resolve_cache_dir)
 from repro.core.runtime import Runtime
 from repro.data.pipeline import DataConfig, MemmapLM, SyntheticLM
 
@@ -23,8 +28,17 @@ COLLECTIVES generic
 
 
 @pytest.fixture()
-def rt(tmp_path):
-    return Runtime(tmp_path / "stevedore")
+def rt(tmp_path, monkeypatch):
+    # a compile cache of this test's own: the hit/miss counts below start
+    # from an empty cache (the shared one lives in the checkout), and JAX's
+    # persistent cache is off so the cold compile really compiles
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield Runtime(tmp_path / "stevedore")
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
 
 
 def test_runtime_build_run_train(rt):
@@ -103,6 +117,60 @@ def test_compile_cache_key_separates_configs(tmp_path):
     assert len({k1, k2, k3}) == 3
 
 
+def test_compile_cache_dir_is_resolved_not_derived_from_root(
+        tmp_path, monkeypatch):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    a, b = Runtime(tmp_path / "a"), Runtime(tmp_path / "b")
+    assert a.compile_cache.root == b.compile_cache.root == tmp_path / "cc"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    fixed = CHECKOUT / ".stevedore" / "compile-cache"
+    assert resolve_cache_dir() == fixed
+    assert Runtime(tmp_path / "c").compile_cache.root == fixed
+    # JAX's own persistent cache is pointed into the checkout too, unless
+    # something already placed it
+    assert jax.config.jax_compilation_cache_dir
+
+
+def test_compile_cache_key_names_the_chip(tmp_path):
+    cache = CompileCache(tmp_path)
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    key = cache.key(image_digest="a" * 64, step_kind="t", mesh=mesh,
+                    args_tree=None)
+    from unittest import mock
+    with mock.patch.object(jax.devices()[0].__class__, "device_kind",
+                           "TPU v5 lite", create=True):
+        other = cache.key(image_digest="a" * 64, step_kind="t", mesh=mesh,
+                          args_tree=None)
+    assert key != other
+
+
+def test_atomic_writes_never_interleave(tmp_path):
+    """Many writers on one path, more threads than cores: every read sees
+    one writer's whole payload, and no temporary file is left behind."""
+    import threading
+    target = tmp_path / "k.exec"
+    payloads = [bytes([i]) * (1 << 16) for i in range(4 * (os.cpu_count() or 1))]
+    seen, errors = [], []
+
+    def write(data):
+        try:
+            for _ in range(5):
+                _atomic_bytes(target, data)
+                seen.append(target.read_bytes())
+        except Exception as e:   # reported below, not lost in the thread
+            errors.append(e)
+
+    threads = [threading.Thread(target=write, args=(p,)) for p in payloads]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors
+    assert all(s in payloads for s in seen)
+    assert [p.name for p in tmp_path.iterdir()] == ["k.exec"]
+
+
 def test_compile_cache_lowered_text_persisted(tmp_path):
     cache = CompileCache(tmp_path)
     mesh = jax.make_mesh((1, 1), ("data", "model"))
@@ -112,6 +180,36 @@ def test_compile_cache_lowered_text_persisted(tmp_path):
     cache.get_or_build(key, lambda: jax.jit(lambda v: v * 2).lower(x))
     text = cache.lowered_text(key)
     assert text and "stablehlo" in text or "module" in text
+
+
+SUBMESH_L1 = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core.compile_cache import CompileCache
+cache = CompileCache(sys.argv[1])
+mesh = jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[2:3])
+rep = NamedSharding(mesh, P())
+x = jax.device_put(jnp.arange(8.0), rep)
+key = cache.key(image_digest="d" * 64, step_kind="t", mesh=mesh, args_tree=x)
+lower = lambda: jax.jit(lambda v: v * 2, in_shardings=rep,
+                        out_shardings=rep).lower(x)
+cold = cache.get_or_build(key, lower)
+warm = cache.get_or_build(key, lower)
+assert cache.stats.last_level == "L1", cache.stats
+assert (warm(x) == cold(x)).all() and warm(x).devices() == {jax.devices()[2]}
+print("SUBMESH_L1_OK")
+"""
+
+
+def test_compile_cache_l1_loads_onto_the_compiled_devices(tmp_path):
+    """An executable built for one chip of four comes back from L1 on that
+    chip, not spread over the host's every device."""
+    r = subprocess.run([sys.executable, "-c", SUBMESH_L1, str(tmp_path)],
+                       capture_output=True, text=True,
+                       env={**os.environ, "PYTHONPATH": "src"}, cwd=".")
+    assert "SUBMESH_L1_OK" in r.stdout, r.stdout + r.stderr[-2000:]
 
 
 # ---------------------------------------------------------------------------

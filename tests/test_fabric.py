@@ -316,3 +316,49 @@ def test_fabric_ledger_conserved_through_churn(rt):
     assert router.outstanding_total == 0
     assert len(router.members) >= router.min_pods
     router.close()
+
+
+# -- one process per chip -----------------------------------------------------
+
+@pytest.mark.parametrize(
+    "initialized,backend,platforms,chips,live,refused", [
+        (True, "tpu", None, 1, 0, "already holds the tpu backend"),
+        (True, "cpu", None, 4, 3, None),       # CPU workers share the host
+        (False, None, "cpu", 4, 3, None),
+        (False, None, None, 4, 0, None),       # the first worker gets them
+        (False, None, None, 4, 1, "each worker claims every chip"),
+        (False, None, None, 0, 5, None),       # no chip to claim
+    ], ids=["parent-holds-chip", "parent-on-cpu", "jax-platforms-cpu",
+            "first-worker", "second-worker", "chipless-host"])
+def test_proc_worker_refused_without_a_chip_of_its_own(
+        monkeypatch, initialized, backend, platforms, chips, live, refused):
+    from repro.orchestrator import fabric
+    monkeypatch.setattr(fabric.xla_bridge, "backends_are_initialized",
+                        lambda: initialized)
+    monkeypatch.setattr(fabric.jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(fabric, "_host_chips", lambda: chips)
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    if refused is None:
+        fabric._check_worker_gets_chip(live)
+    else:
+        with pytest.raises(RuntimeError, match=refused):
+            fabric._check_worker_gets_chip(live)
+
+
+def test_proc_spawner_refuses_before_starting_a_process(monkeypatch, tmp_path):
+    """A refused worker is refused at once: no process is started, so
+    nothing can hang waiting for a chip."""
+    from repro.orchestrator import fabric
+    monkeypatch.setattr(fabric.xla_bridge, "backends_are_initialized",
+                        lambda: True)
+    monkeypatch.setattr(fabric.jax, "default_backend", lambda: "tpu")
+    started = []
+    monkeypatch.setattr(fabric, "ProcTransport",
+                        lambda *a, **k: started.append(a))
+    spawn = fabric.proc_spawner(tmp_path, imagefile="FROM scratch\n")
+    with pytest.raises(RuntimeError, match="--fabric loopback"):
+        spawn("pod-0", 0)
+    assert started == []

@@ -169,3 +169,12 @@ def test_matmul_vs_ref(case):
     ref = matmul_ref(a, b)
     err = float(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32)).max())
     assert err < (2.0 if dt == jnp.bfloat16 else 1e-3)
+
+
+def test_matmul_refuses_a_chip_without_a_block_table(monkeypatch):
+    """A TPU kind missing from the table is an error, not v5e's blocks."""
+    from repro.kernels.matmul import ops
+    monkeypatch.setattr(ops, "_platform", lambda: "TPU v99")
+    a = jnp.ones((8, 8), jnp.float32)
+    with pytest.raises(ValueError, match="no matmul block table for 'TPU v99'"):
+        ops.matmul(a, a)
