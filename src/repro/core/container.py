@@ -551,30 +551,35 @@ class Container:
         image whose serving-relevant layers are unchanged deserializes the
         executable instead of re-tracing (see _serve_cache_digest).
         """
+        from repro.orchestrator.obs.tracing import span
         from repro.serve.serve_step import dispatch_class
         acct = self.serve_compile_stats.setdefault(
             dispatch_class(kind), {"hits": 0, "misses": 0, "seconds": 0.0})
-        if self.compile_cache is None:
-            import time
-            t0 = time.perf_counter()
-            exe = self.lower_serve_step(kind, **shapes).compile()
-            acct["misses"] += 1
-            acct["seconds"] += time.perf_counter() - t0
+        with span("compile", step=kind) as sp:
+            if self.compile_cache is None:
+                import time
+                t0 = time.perf_counter()
+                exe = self.lower_serve_step(kind, **shapes).compile()
+                acct["misses"] += 1
+                acct["seconds"] += time.perf_counter() - t0
+                sp.set_metadata(hit=False)
+                return exe
+            sig = ",".join(f"{k}={v}" for k, v in sorted(shapes.items())
+                           if v is not None)
+            key = self.compile_cache.key(
+                image_digest=self._serve_cache_digest(),
+                step_kind=f"serve:{kind}[{sig}]",
+                mesh=self.mesh, args_tree=None)
+            stats = self.compile_cache.stats
+            hits0, miss0 = stats.hits_l1 + stats.hits_l2, stats.misses
+            exe = self.compile_cache.get_or_build(
+                key, lambda: self.lower_serve_step(kind, **shapes))
+            hits = (stats.hits_l1 + stats.hits_l2) - hits0
+            acct["hits"] += hits
+            acct["misses"] += stats.misses - miss0
+            acct["seconds"] += stats.last_seconds
+            sp.set_metadata(hit=hits > 0)
             return exe
-        sig = ",".join(f"{k}={v}" for k, v in sorted(shapes.items())
-                       if v is not None)
-        key = self.compile_cache.key(
-            image_digest=self._serve_cache_digest(),
-            step_kind=f"serve:{kind}[{sig}]",
-            mesh=self.mesh, args_tree=None)
-        stats = self.compile_cache.stats
-        hits0, miss0 = stats.hits_l1 + stats.hits_l2, stats.misses
-        exe = self.compile_cache.get_or_build(
-            key, lambda: self.lower_serve_step(kind, **shapes))
-        acct["hits"] += (stats.hits_l1 + stats.hits_l2) - hits0
-        acct["misses"] += stats.misses - miss0
-        acct["seconds"] += stats.last_seconds
-        return exe
 
     # -- lowering (the dry-run entry) ------------------------------------------
     def lower_step(self, kind: str | None = None, donate: bool = True):

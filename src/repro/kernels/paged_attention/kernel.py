@@ -138,6 +138,7 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_kv, g, hd), q.dtype),
         interpret=interpret,

@@ -23,6 +23,12 @@ recorded with ``serve --trace out.json`` opens directly in Perfetto /
 ``chrome://tracing``: one process row per pod, one thread row per
 request, with queue/prefill/decode spans carrying pod/replica/slot/
 page-count/prefix-hit attributes in ``args``.
+
+``span`` is the other clock: it marks a stretch of the program's host work
+(``repro.step``, ``repro.decode.wait``, ...) in the JAX profiler's trace,
+in seconds on the clock of the device's ops, so that an idle stretch of
+the device can be put down to the host work that held it. It records
+nothing outside a profiler session and never touches a ``TraceBuffer``.
 """
 
 from __future__ import annotations
@@ -114,6 +120,16 @@ class SpanEvent:
             if k == key:
                 return v
         return default
+
+
+def span(name: str, **attrs):
+    """A host span ``repro.<name>`` on the profiler's clock, as a context
+    manager; ``attrs`` are the counts at its boundary. Counts known only
+    later go in through ``set_metadata(**attrs)`` on the value that
+    ``with span(...) as sp`` binds. Costs about a microsecond when no
+    profiler session is active."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(f"repro.{name}", **attrs)
 
 
 class TraceBuffer:

@@ -21,7 +21,7 @@ from pathlib import Path
 
 from repro.core.image import EnvImage
 from repro.orchestrator.obs.metrics import MetricsRegistry
-from repro.orchestrator.obs.tracing import TraceBuffer
+from repro.orchestrator.obs.tracing import TraceBuffer, span
 from repro.orchestrator.scheduler import SlotEngine
 
 
@@ -146,15 +146,16 @@ class Pod:
     def write_state(self, final: bool = False) -> Path:
         """Persist status; ``final=True`` stamps a terminal phase so ``ps``
         never misreports the pod after OS pid reuse."""
-        d = Path(self.runtime.root) / "pods"
-        d.mkdir(parents=True, exist_ok=True)
-        p = d / f"{self.pod_id}.json"
-        status = self.status()
-        if final:
-            status["phase"] = "exited"
-        # atomic: state refreshes every scheduler tick and a concurrent
-        # `repro ps` must never see a half-written file
-        tmp = p.with_suffix(".tmp")
-        tmp.write_text(json.dumps(status, indent=2))
-        os.replace(tmp, p)
+        with span("write_state"):
+            d = Path(self.runtime.root) / "pods"
+            d.mkdir(parents=True, exist_ok=True)
+            p = d / f"{self.pod_id}.json"
+            status = self.status()
+            if final:
+                status["phase"] = "exited"
+            # atomic: state refreshes every scheduler tick and a concurrent
+            # `repro ps` must never see a half-written file
+            tmp = p.with_suffix(".tmp")
+            tmp.write_text(json.dumps(status, indent=2))
+            os.replace(tmp, p)
         return p

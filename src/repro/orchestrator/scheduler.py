@@ -27,7 +27,7 @@ import numpy as np
 from repro.orchestrator.obs.metrics import MetricsRegistry
 from repro.orchestrator.obs.report import (ITL_HIST, TICK_HIST,
                                            observe_completion)
-from repro.orchestrator.obs.tracing import TraceBuffer
+from repro.orchestrator.obs.tracing import TraceBuffer, span
 from repro.orchestrator.page_pool import PagePool
 from repro.orchestrator.prefix_registry import PrefixMatch
 from repro.orchestrator.request_queue import GenRequest, RequestQueue
@@ -418,6 +418,11 @@ class SlotEngine:
         prompt plus every token generated before the pause except the last
         -- that one stays the decode cursor, exactly where the unpreempted
         run left it, so the continuation is token-for-token identical."""
+        with span("prefill", rid=req.rid) as sp:
+            return self._start(req, tick, sp)
+
+    def _start(self, req: GenRequest, tick: int, sp) -> bool:
+        """``start`` inside its ``repro.prefill`` span ``sp``."""
         # chunked decode can overshoot a finished request by chunk-1 writes;
         # the scheduler pre-screens, so tripping this is an internal bug
         if not self.fits(req):
@@ -469,24 +474,28 @@ class SlotEngine:
                 self._prefills[key] = prefill
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :S] = sfx
-            self.pool.reserve(slot, self.pages_needed(req) - k)
-            self.pool.share_chain(slot, hit)    # restores spilled nodes
-            self.pool.alloc_upto(slot, P - 1)   # private suffix pages
-            self._drain_tier_events(req.rid, tick)
+            with span("prefill.insert"):
+                self.pool.reserve(slot, self.pages_needed(req) - k)
+                self.pool.share_chain(slot, hit)    # restores spilled nodes
+                self.pool.alloc_upto(slot, P - 1)   # private suffix pages
+                self._drain_tier_events(req.rid, tick)
             t0 = time.perf_counter()
-            first, small = prefill(
-                self.params, self.cache, jnp.asarray(toks), jnp.int32(S),
-                jnp.asarray([n.page for n in hit.all_nodes()],
-                            dtype=jnp.int32))
+            with span("prefill.dispatch"):
+                first, small = prefill(
+                    self.params, self.cache, jnp.asarray(toks), jnp.int32(S),
+                    jnp.asarray([n.page for n in hit.all_nodes()],
+                                dtype=jnp.int32))
             # the suffix prefill READS the live pool and the scatter below
             # DONATES it: force completion of BOTH outputs (small reads the
             # chain pages too) before re-using the buffer
-            first, small = jax.block_until_ready((first, small))
+            with span("prefill.wait"):
+                first, small = jax.block_until_ready((first, small))
             first = int(first[0])
-            self.pool.unpin()   # partial boundary page consumed by small
-            np_ = -(-(frac + bucket) // self.page_size)
-            row = jnp.asarray(self.pool.table[slot, k:k + np_])
-            self.cache = _insert_pages_jit(self.cache, small, row)
+            with span("prefill.insert"):
+                self.pool.unpin()   # partial boundary page consumed by small
+                np_ = -(-(frac + bucket) // self.page_size)
+                row = jnp.asarray(self.pool.table[slot, k:k + np_])
+                self.cache = _insert_pages_jit(self.cache, small, row)
             start_pos = P
             toks_p = self._prefix_tokens(req)
             kc = len(toks_p) // self.page_size  # declared complete blocks
@@ -514,6 +523,7 @@ class SlotEngine:
                     slot, hit.nodes[-1] if hit.nodes else None,
                     [toks_p[i * ps:(i + 1) * ps] for i in range(k, kc)])
             self.prefill_s += time.perf_counter() - t0
+            sp.set_metadata(positions=S, bucket=bucket, prefix_hit=True)
             self.trace.record(req.rid, "prefill", tick, replica=self.name,
                               slot=slot, positions=S, bucket=bucket,
                               pages=self.pages_needed(req) - k,
@@ -545,20 +555,28 @@ class SlotEngine:
                            jnp.int32(req.frontend_len))
 
             t0 = time.perf_counter()
-            first, small = prefill(self.params, jnp.asarray(toks),
-                                   jnp.int32(P), *fe_args)
+            with span("prefill.dispatch"):
+                first, small = prefill(self.params, jnp.asarray(toks),
+                                       jnp.int32(P), *fe_args)
             start_pos = req.frontend_len + P
-            if self.paged:
-                # bulk prefix+prompt allocation, then one page-major scatter
-                self.pool.reserve(slot, self.pages_needed(req))
-                self.pool.alloc_upto(slot, start_pos - 1)
-                np_ = -(-(bucket + self.fe_len) // self.page_size)
-                row = jnp.asarray(self.pool.table[slot, :np_])
-                self.cache = _insert_pages_jit(self.cache, small, row)
-            else:
-                self.cache = self._insert(self.cache, small, jnp.int32(slot))
-            first = int(jax.block_until_ready(first)[0])
+            with span("prefill.insert"):
+                if self.paged:
+                    # bulk prefix+prompt allocation, then one page-major
+                    # scatter
+                    self.pool.reserve(slot, self.pages_needed(req))
+                    self.pool.alloc_upto(slot, start_pos - 1)
+                    np_ = -(-(bucket + self.fe_len) // self.page_size)
+                    row = jnp.asarray(self.pool.table[slot, :np_])
+                    self.cache = _insert_pages_jit(self.cache, small, row)
+                else:
+                    self.cache = self._insert(self.cache, small,
+                                              jnp.int32(slot))
+            with span("prefill.wait"):
+                first = jax.block_until_ready(first)
+            first = int(first[0])
             self.prefill_s += time.perf_counter() - t0
+            sp.set_metadata(positions=req.frontend_len + P, bucket=bucket,
+                            prefix_hit=False)
             self._c_positions.inc(req.frontend_len + P)
             self._c_prefill_disp.inc()
             if self.paged:
@@ -617,28 +635,48 @@ class SlotEngine:
         tokens are discarded here (bounded, counted waste)."""
         if not self.active:
             return []
+        with span("decode", active=len(self.active), chunk=self.chunk):
+            return self._tick(tick)
+
+    def _tick(self, tick: int) -> list[GenRequest]:
+        """``tick`` inside its ``repro.decode`` span, with an active slot."""
         t0 = time.perf_counter()
         if self.paged:
             # alloc-on-write, one chunk ahead: every write position of this
             # dispatch (pos..pos+chunk-1) must be mapped before the kernel
             # runs; pages come out of the request's admission reservation,
             # so this can never fail mid-flight
-            for slot in self.active:
-                self.pool.alloc_upto(slot, int(self.pos[slot]) + self.chunk - 1)
-                self._drain_tier_events(self.active[slot].rid, tick)
-            toks, _, _, self.cache = self.decode(
-                self.params, self.cache,
-                jnp.asarray(self.cur_tok[:, None]), jnp.asarray(self.pos),
-                jnp.asarray(self.pool.table))
+            with span("decode.alloc"):
+                for slot in self.active:
+                    self.pool.alloc_upto(
+                        slot, int(self.pos[slot]) + self.chunk - 1)
+                    self._drain_tier_events(self.active[slot].rid, tick)
+            with span("decode.dispatch"):
+                toks, _, _, self.cache = self.decode(
+                    self.params, self.cache,
+                    jnp.asarray(self.cur_tok[:, None]), jnp.asarray(self.pos),
+                    jnp.asarray(self.pool.table))
         else:
-            toks, _, _, self.cache = self.decode(
-                self.params, self.cache,
-                jnp.asarray(self.cur_tok[:, None]), jnp.asarray(self.pos))
-        toks = np.asarray(jax.block_until_ready(toks))   # (n_slots, chunk)
+            with span("decode.dispatch"):
+                toks, _, _, self.cache = self.decode(
+                    self.params, self.cache,
+                    jnp.asarray(self.cur_tok[:, None]), jnp.asarray(self.pos))
+        with span("decode.wait"):
+            toks = jax.block_until_ready(toks)
+        with span("decode.readback"):
+            toks = np.asarray(toks)                      # (n_slots, chunk)
         self.decode_s += time.perf_counter() - t0
         self._c_decode_ticks.inc(self.chunk)
         self._c_decode_disp.inc()
+        with span("decode.walk") as sp:
+            n0 = self._c_tokens.value
+            finished = self._walk(toks, tick)
+            sp.set_metadata(tokens=self._c_tokens.value - n0)
+        return finished
 
+    def _walk(self, toks: np.ndarray, tick: int) -> list[GenRequest]:
+        """Hand each active slot its chunk of ``toks``; returns the
+        requests that completed."""
         finished = []
         # advance ACTIVE rows only: free slots stay parked at 0, so an
         # engine idling for hours never walks a row position past max_len
@@ -836,56 +874,63 @@ class ContinuousScheduler:
 
     # -- one global tick ------------------------------------------------------
     def step(self) -> list[GenRequest]:
+        with span("step", tick=self.tick):
+            return self._step()
+
+    def _step(self) -> list[GenRequest]:
         done: list[GenRequest] = []
         # admission: FIFO across the pod, capped prefills per tick
         admitted = rejected = 0
         while admitted < self.fairness_cap and self.queue.has_ready(self.tick):
             req = self.queue.peek_ready(self.tick)
-            # permanent infeasibility is screened BEFORE the free-slot gate:
-            # a request that exceeds every engine's slab / page-table span /
-            # pool can NEVER run, so it must be rejected even when all slots
-            # are busy -- gating on occupancy let an un-servable head stall
-            # every feasible request behind it until a slot freed
-            if not any(e.fits(req) for e in self.pod.engines):
-                self.queue.pop_ready(self.tick)
-                self.reject(req)
-                rejected += 1
-                continue
-            # admission-deadline SLO: a queued head that can no longer be
-            # admitted in time is shed, not served uselessly late. Resumes
-            # are exempt -- their first token already left on time.
-            if (req.state == "queued" and req.deadline_ticks is not None
-                    and self.tick > max(req.arrival, req.submit_tick)
-                    + req.deadline_ticks):
-                self.queue.pop_ready(self.tick)
-                self.shed(req, "deadline")
-                rejected += 1
-                continue
-            engines = [e for e in self.pod.engines if e.has_free()]
-            ready = [e for e in engines if e.can_start(req)]
-            if not ready:
-                # feasible but no slot / no pages free right now: hold the
-                # head -- unless it is an interactive head blocked behind
-                # running batch work, in which case page-level preemption
-                # pauses the youngest batch request to make room (strict
-                # QoS; equal-priority work is never preempted)
-                if self._try_preempt(req):
+            with span("admit", rid=req.rid, queued_ticks=self.tick - max(
+                    req.arrival, req.submit_tick)):
+                # permanent infeasibility is screened BEFORE the free-slot
+                # gate: a request that exceeds every engine's slab /
+                # page-table span / pool can NEVER run, so it must be
+                # rejected even when all slots are busy -- gating on
+                # occupancy let an un-servable head stall every feasible
+                # request behind it until a slot freed
+                if not any(e.fits(req) for e in self.pod.engines):
+                    self.queue.pop_ready(self.tick)
+                    self.reject(req)
+                    rejected += 1
                     continue
-                break
-            # least-loaded engine keeps replica occupancy balanced without
-            # breaking FIFO (the *request* order is still queue order);
-            # an engine whose registry already holds the request's prefix
-            # wins ties-or-better, DEEPEST match first (prefix affinity
-            # WITHIN the pod -- each replica's page pool is its own)
-            def _affinity(e):
-                m = e.prefix_hit(req)
-                return (-m.tokens_matched if m is not None else 0,
-                        len(e.active))
-            eng = min(ready, key=_affinity)
-            self.queue.pop_ready(self.tick)
-            if req.state == "queued":   # resumes were already counted
-                self.queue.admitted += 1
-                self.admission_order.append(req.rid)
+                # admission-deadline SLO: a queued head that can no longer be
+                # admitted in time is shed, not served uselessly late. Resumes
+                # are exempt -- their first token already left on time.
+                if (req.state == "queued" and req.deadline_ticks is not None
+                        and self.tick > max(req.arrival, req.submit_tick)
+                        + req.deadline_ticks):
+                    self.queue.pop_ready(self.tick)
+                    self.shed(req, "deadline")
+                    rejected += 1
+                    continue
+                engines = [e for e in self.pod.engines if e.has_free()]
+                ready = [e for e in engines if e.can_start(req)]
+                if not ready:
+                    # feasible but no slot / no pages free right now: hold the
+                    # head -- unless it is an interactive head blocked behind
+                    # running batch work, in which case page-level preemption
+                    # pauses the youngest batch request to make room (strict
+                    # QoS; equal-priority work is never preempted)
+                    if self._try_preempt(req):
+                        continue
+                    break
+                # least-loaded engine keeps replica occupancy balanced without
+                # breaking FIFO (the *request* order is still queue order);
+                # an engine whose registry already holds the request's prefix
+                # wins ties-or-better, DEEPEST match first (prefix affinity
+                # WITHIN the pod -- each replica's page pool is its own)
+                def _affinity(e):
+                    m = e.prefix_hit(req)
+                    return (-m.tokens_matched if m is not None else 0,
+                            len(e.active))
+                eng = min(ready, key=_affinity)
+                self.queue.pop_ready(self.tick)
+                if req.state == "queued":   # resumes were already counted
+                    self.queue.admitted += 1
+                    self.admission_order.append(req.rid)
             if eng.start(req, self.tick):
                 done.append(req)
             admitted += 1
@@ -893,8 +938,10 @@ class ContinuousScheduler:
         for eng in self.pod.engines:
             done.extend(eng.tick(self.tick))
         self.completed.extend(done)
-        for req in done:
-            self._observe(req)
+        if done:
+            with span("observe", requests=len(done)):
+                for req in done:
+                    self._observe(req)
         self._g_queue.set(self.queue.pending)
         self.tick += 1
         # keep `repro ps` honest without putting file I/O in every tick:

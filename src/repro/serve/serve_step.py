@@ -20,6 +20,10 @@ The slot variants treat the batch dimension as a bank of independent
 (``pos`` per row), so requests of different lengths decode in lockstep and
 a finished slot can be refilled without touching its neighbours.
 
+Every model call inside a step runs under ``jax.named_scope("prefill")``
+or ``("decode")``, so each compiled op names its phase in its ``op_name``
+metadata, whatever the jitted step is called.
+
 The ``*_paged`` variants replace the contiguous per-slot slabs with a
 global page pool + per-slot page table (kernels/paged_attention): same
 token-for-token semantics, but slots share KV memory at page granularity
@@ -77,16 +81,19 @@ class ServeStepBuilder:
 
     def build_prefill(self, cache_len: int) -> Callable:
         def prefill(params, tokens, frontend_embeds=None):
-            logits, cache, _ = self.model.forward(
-                params, tokens, frontend_embeds=frontend_embeds,
-                collect_cache=True, cache_len=cache_len)
+            with jax.named_scope("prefill"):
+                logits, cache, _ = self.model.forward(
+                    params, tokens, frontend_embeds=frontend_embeds,
+                    collect_cache=True, cache_len=cache_len)
             return logits[:, -1], cache
 
         return prefill
 
     def build_decode(self) -> Callable:
         def decode(params, cache, tokens, idx):
-            logits, new_cache = self.model.decode_step(params, cache, tokens, idx)
+            with jax.named_scope("decode"):
+                logits, new_cache = self.model.decode_step(
+                    params, cache, tokens, idx)
             return logits[:, -1], new_cache
 
         return decode
@@ -121,18 +128,20 @@ class ServeStepBuilder:
 
         if frontend_len:
             def prefill_slot(params, tokens, length, frontend_embeds, fe_len):
-                logits, cache, _ = self.model.forward(
-                    params, tokens, frontend_embeds=frontend_embeds,
-                    frontend_len=fe_len, collect_cache=True,
-                    cache_len=cache_len)
+                with jax.named_scope("prefill"):
+                    logits, cache, _ = self.model.forward(
+                        params, tokens, frontend_embeds=frontend_embeds,
+                        frontend_len=fe_len, collect_cache=True,
+                        cache_len=cache_len)
                 return _sample_at(logits,
                                   jnp.asarray(fe_len + length - 1)), cache
 
             return prefill_slot
 
         def prefill_slot(params, tokens, length):
-            logits, cache, _ = self.model.forward(
-                params, tokens, collect_cache=True, cache_len=cache_len)
+            with jax.named_scope("prefill"):
+                logits, cache, _ = self.model.forward(
+                    params, tokens, collect_cache=True, cache_len=cache_len)
             return _sample_at(logits, jnp.asarray(length - 1)), cache
 
         return prefill_slot
@@ -220,10 +229,11 @@ class ServeStepBuilder:
 
             def prefill_suffix_paged(params, pool, tokens, length,
                                      prefix_pages):
-                logits, cache, _ = self.model.forward(
-                    params, tokens, collect_cache=True, cache_len=span,
-                    prefix_kv=pool, prefix_pages=prefix_pages,
-                    prefix_len=prefix_len)
+                with jax.named_scope("prefill"):
+                    logits, cache, _ = self.model.forward(
+                        params, tokens, collect_cache=True, cache_len=span,
+                        prefix_kv=pool, prefix_pages=prefix_pages,
+                        prefix_len=prefix_len)
                 last = jnp.take_along_axis(
                     logits, jnp.asarray(length - 1).reshape(-1, 1, 1),
                     axis=1)[:, 0]
@@ -278,8 +288,9 @@ class ServeStepBuilder:
         vocab = self.model.cfg.vocab_size
 
         def decode_slots_paged(params, cache, tokens, pos, page_table):
-            logits, new_cache = self.model.decode_step(
-                params, cache, tokens, pos, page_table=page_table)
+            with jax.named_scope("decode"):
+                logits, new_cache = self.model.decode_step(
+                    params, cache, tokens, pos, page_table=page_table)
             return greedy_sample(logits[:, -1], vocab), new_cache
 
         return decode_slots_paged
@@ -294,8 +305,9 @@ class ServeStepBuilder:
         def decode_chunk_paged(params, cache, tokens, pos, page_table):
             def body(carry, _):
                 cache, tok, pos = carry
-                logits, cache = self.model.decode_step(
-                    params, cache, tok, pos, page_table=page_table)
+                with jax.named_scope("decode"):
+                    logits, cache = self.model.decode_step(
+                        params, cache, tok, pos, page_table=page_table)
                 nxt = greedy_sample(logits[:, -1], vocab)[:, None]
                 return (cache, nxt, pos + 1), nxt[:, 0]
 

@@ -644,3 +644,113 @@ def test_serve_trace_flag_writes_valid_trace(rt, tmp_path):
     assert d["ttft_p99_ticks"] >= 0 and d["itl_p50_ticks"] >= 0
     assert out["latency_count"] == 5
     assert "tokens_wasted" in out
+
+
+# ---------------------------------------------------------------------------
+# program spans on the profiler's clock (obs.tracing.span)
+# ---------------------------------------------------------------------------
+
+# every span one step writes, with the span it sits in
+SPAN_PARENTS = {
+    "repro.step": None, "repro.admit": "repro.step",
+    "repro.prefill": "repro.step", "repro.compile": "repro.prefill",
+    "repro.prefill.dispatch": "repro.prefill",
+    "repro.prefill.wait": "repro.prefill",
+    "repro.prefill.insert": "repro.prefill",
+    "repro.decode": "repro.step", "repro.decode.alloc": "repro.decode",
+    "repro.decode.dispatch": "repro.decode",
+    "repro.decode.wait": "repro.decode",
+    "repro.decode.readback": "repro.decode",
+    "repro.decode.walk": "repro.decode",
+    "repro.observe": "repro.step", "repro.write_state": "repro.step"}
+
+
+def _program_spans(log_dir):
+    """[name, parent name, attributes] of every ``repro.*`` host event in
+    the profiler's trace under ``log_dir``, in start order."""
+    import glob
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    evs = sorted(([e.name, e.start_ns, e.start_ns + e.duration_ns,
+                   dict(e.stats)]
+                  for plane in ProfileData.from_file(path).planes
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith("repro.")),
+                 key=lambda e: (e[1], -e[2]))
+    out, stack = [], []
+    for name, s, end, attrs in evs:
+        while stack and stack[-1][1] <= s:
+            stack.pop()
+        out.append([name, stack[-1][0] if stack else None, attrs])
+        stack.append((name, end))
+    return out
+
+
+@pytest.mark.orchestrator
+def test_step_writes_program_spans_nested_with_attributes(rt, tmp_path):
+    """One scheduler step of a tiny paged model under the profiler: it
+    admits a request, prefills it (compiling the bucket), decodes the
+    chunk that completes it, observes it and writes the pod's state. Every
+    span of that work is in the trace, inside the span of its caller, with
+    the counts at its boundary."""
+    import jax
+
+    from repro.orchestrator import ContinuousScheduler, GenRequest, Pod
+    pod = Pod(rt, "stable", replicas=1, n_slots=2, max_len=56, paged=True,
+              page_size=8, decode_chunk=4)
+    sched = ContinuousScheduler(pod)
+    req = GenRequest(rid=5, prompt=np.arange(6), max_new_tokens=2)
+    sched.submit(req)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sched.step()
+    finally:
+        jax.profiler.stop_trace()
+    assert req.state == "done"
+    spans = _program_spans(tmp_path)
+    assert sorted({n for n, _, _ in spans}) == sorted(SPAN_PARENTS)
+    for name, parent, _ in spans:
+        assert parent == SPAN_PARENTS[name], name
+    attrs = {n: a for n, _, a in spans}
+    assert attrs["repro.step"] == {"tick": 0}
+    assert attrs["repro.admit"] == {"rid": 5, "queued_ticks": 0}
+    assert attrs["repro.prefill"] == {"rid": 5, "positions": 6,
+                                      "bucket": 16, "prefix_hit": 0}
+    assert attrs["repro.compile"]["step"] == "prefill_slot_paged"
+    assert attrs["repro.compile"]["hit"] in (0, 1)
+    assert attrs["repro.decode"] == {"active": 1, "chunk": 4}
+    assert attrs["repro.decode.walk"] == {"tokens": 1}
+    assert attrs["repro.observe"] == {"requests": 1}
+    # one of each per step: the step's work is not cut per token
+    assert len(spans) == len(SPAN_PARENTS)
+
+
+@pytest.mark.orchestrator
+def test_span_log_replays_byte_identical_under_the_profiler(rt, tmp_path):
+    """The tick-clocked span log of one request trace is the same bytes
+    whether the profiler records the program's spans or not."""
+    import jax
+
+    from repro.orchestrator import ContinuousScheduler, Pod
+    from repro.orchestrator.obs import dump_span_log
+
+    def serve_once(path, profile):
+        pod = Pod(rt, "stable", replicas=1, n_slots=3, max_len=56,
+                  paged=True, page_size=8, pod_id="pod-replay")
+        sched = ContinuousScheduler(pod, fairness_cap=3)
+        sched.submit(_requests(np.random.default_rng(11), 8))
+        if profile:
+            jax.profiler.start_trace(str(tmp_path / "profile"))
+        try:
+            sched.run(max_ticks=2000)
+        finally:
+            if profile:
+                jax.profiler.stop_trace()
+        return dump_span_log(pod.trace, path).read_bytes()
+
+    plain = serve_once(tmp_path / "plain.json", False)
+    traced = serve_once(tmp_path / "traced.json", True)
+    assert traced == plain
+    assert json.loads(plain)["events"]
+    assert _program_spans(tmp_path / "profile")
