@@ -1,13 +1,13 @@
 """Public entry point for paged decode attention.
 
-On TPU the Pallas kernel streams KV pages through the scalar-prefetch
-pipeline; elsewhere (this container: CPU) the XLA oracle runs instead --
-NOT the interpreted kernel, which would put an interpreter in the decode
-hot loop of every serving tick. The oracle gathers pages into contiguous
-form inside the jitted step, which XLA fuses; numerics are identical to
-``models.attention._sdpa_dense`` so paged and contiguous slot decode agree
-token-for-token (tests/test_paged_attention.py pins all three against each
-other).
+On TPU the Pallas kernel copies each slot's live KV pages into VMEM,
+prefetching the next block under the current one; elsewhere (the CPU)
+the XLA oracle runs instead -- NOT the interpreted kernel, which would put
+an interpreter in the decode hot loop of every serving tick. The oracle
+gathers pages into contiguous form inside the jitted step, which XLA
+fuses; numerics are identical to ``models.attention._sdpa_dense`` so paged
+and contiguous slot decode agree token-for-token
+(tests/test_paged_attention.py pins all three against each other).
 """
 
 from __future__ import annotations

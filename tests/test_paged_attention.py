@@ -17,6 +17,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.paged_attention import kernel as paged_kernel
 from repro.kernels.paged_attention.kernel import paged_attention_pallas
 from repro.kernels.paged_attention.ops import paged_attention
 from repro.kernels.paged_attention.ref import gather_pages, paged_attention_ref
@@ -80,6 +81,57 @@ def test_paged_kernel_vs_ref(case):
     err = float(jnp.abs(out.astype(jnp.float32)
                         - ref.astype(jnp.float32)).max())
     assert err < _tol(dt), err
+
+
+# the served schedule walks several blocks of ``ppb`` table entries per
+# slot; these cases pin ppb small (monkeypatched) so that every slot
+# crosses blocks, its prefetch runs into the same slot's next block and
+# into the next slot's first, and the table's width is no multiple of it.
+# A slot of length 1 is a parked one: free slots decode at position 0
+# through an all-garbage table row.
+BLOCK_CASES = [
+    # B, n_kv, g, hd, page_size, max_pages, ppb, window, dtype, lengths
+    (3, 2, 2, 16, 16, 7, 2, 0, jnp.float32, [1, 112, 57]),
+    (4, 2, 2, 16, 16, 13, 4, 0, jnp.float32, [208, 3, 64, 65]),
+    (4, 1, 4, 32, 16, 6, 2, 0, jnp.float32, [32, 33, 64, 65]),
+    (2, 32, 1, 80, 16, 5, 2, 0, jnp.bfloat16, [80, 33]),
+    (2, 8, 8, 128, 16, 6, 4, 0, jnp.bfloat16, [96, 65]),
+    (3, 2, 2, 16, 16, 13, 2, 100, jnp.float32, [200, 208, 70]),
+    (2, 2, 3, 16, 8, 12, 2, 40, jnp.bfloat16, [96, 41]),
+    # parked slots beside long ones, at the cells' head geometries
+    (4, 2, 2, 16, 16, 7, 2, 0, jnp.float32, [1, 100, 1, 112]),
+    (4, 32, 1, 80, 16, 7, 2, 0, jnp.bfloat16, [1, 100, 1, 112]),
+    (4, 8, 8, 128, 16, 7, 2, 0, jnp.bfloat16, [1, 100, 1, 112]),
+]
+
+
+@pytest.mark.parametrize("case", BLOCK_CASES, ids=str)
+def test_paged_kernel_blocks_vs_ref(case, monkeypatch):
+    B, n_kv, g, hd, ps, mp, ppb, window, dt, lengths = case
+    monkeypatch.setattr(paged_kernel, "pages_per_block", lambda *a: ppb)
+    rng = np.random.default_rng(7)
+    q, k, v, table, lens = _random_paged(
+        rng, B, n_kv, g, hd, ps, mp, np.asarray(lengths, np.int32))
+    table = jnp.where(lens[:, None] == 1, GARBAGE_PAGE, table)
+    q, k, v = (x.astype(dt) for x in (q, k, v))
+    out = paged_attention_pallas(q, k, v, table, lens, window=window,
+                                 interpret=True)
+    ref = paged_attention_ref(q, k, v, table, lens, window=window)
+    err = float(jnp.abs(out.astype(jnp.float32)
+                        - ref.astype(jnp.float32)).max())
+    assert err < _tol(dt), err
+
+
+@pytest.mark.parametrize("n_kv,hd,mp,want", [
+    (8, 128, 161, 32),     # deepseek-67b stage: 64 KiB of K+V a page
+    (32, 80, 193, 8),      # stablelm-3b: hd 80 lies in 128 lanes
+    (8, 128, 6, 4),        # no wider than the table
+    (64, 1024, 100, 1),    # a page over the budget still makes a block
+], ids=["deepseek", "stablelm", "narrow-table", "huge-page"])
+def test_pages_per_block_from_shapes(n_kv, hd, mp, want):
+    ppb = paged_kernel.pages_per_block(n_kv, 16, hd, 2, mp)
+    assert ppb == want
+    assert ppb & (ppb - 1) == 0
 
 
 @pytest.mark.parametrize("page_size", [8, 16, 64])
